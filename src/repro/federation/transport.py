@@ -148,10 +148,13 @@ def _subprocess_worker(blob: bytes) -> bytes:
 
 
 class SubprocessTransport(TransportBase):
-    """One worker process per party (spawn start method: safe after the
-    parent has initialized JAX).  Workers re-import and re-jit, so cold
-    cost is high — this transport exists to make the cross-silo
-    deployment real, not to win single-host benchmarks.
+    """One worker process per party (spawn start method).  Workers
+    re-import and re-jit, so cold cost is high — this transport exists
+    to make the cross-silo deployment real, not to win single-host
+    benchmarks.  CPU hosts only: an accelerator belongs to the one
+    process that first touches JAX, so a parent on a TPU backend holds
+    the chip and the spawned parties would fail on the TPU library's
+    lock or hang; the constructor refuses that case up front.
 
     Cleanup contract: when any party raises, the whole worker pool is
     terminated on the spot — the old executor-based round left the
@@ -160,6 +163,14 @@ class SubprocessTransport(TransportBase):
     name = "subprocess"
 
     def __init__(self, parallelism: Optional[int] = None):
+        import jax
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "SubprocessTransport cannot run on a TPU backend: this "
+                "process already holds the chip, so every spawned party "
+                "would fail on the TPU library's lock or hang.  Run the "
+                "parties in this process (transport 'inprocess', "
+                "'thread' or 'socket') or as separate hosts.")
         self.parallelism = parallelism
 
     def run_round(self, parties, keys, X_public, num_queries, engine):

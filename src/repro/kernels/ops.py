@@ -254,13 +254,18 @@ def _top2_of(scores, argmax_labels, num_classes):
 
 
 def _votes_kernel(preds, num_classes, noise, interpret):
+    """The blocked kernel over 128-query blocks: T is padded up to the
+    block (padded queries vote class 0 and are sliced off), so no query
+    count ever forces a whole-axis block into VMEM."""
     T = preds.shape[1]
     if noise is None:
         noise = jnp.zeros((T, num_classes), jnp.float32)
-    bt = 128 if T % 128 == 0 else T
+    preds, _ = _pad_to(preds, 128, 1)
+    noise, _ = _pad_to(noise, 128, 0)
     bu = 512 if num_classes % 512 == 0 else num_classes
-    return _va.vote_aggregate(preds, noise, num_classes=num_classes,
-                              block_t=bt, block_u=bu, interpret=interpret)
+    outs = _va.vote_aggregate(preds, noise, num_classes=num_classes,
+                              block_t=128, block_u=bu, interpret=interpret)
+    return tuple(o[:T] for o in outs)
 
 
 def votes(preds, num_classes, noise=None, *, impl="auto"):

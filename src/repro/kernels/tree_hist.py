@@ -11,13 +11,18 @@ XLA lowering is one giant 1-D scatter-add over an (N, F) broadcast of
 w — memory-bound and serialized by the scatter loop.
 
 TPU-native reformulation (the ``vote_aggregate`` pattern): the grid
-walks (feature-block, sample-block) with samples innermost.  Each step
-builds two one-hot operands on the VPU — the (bs, num_nodes) node mask
-scaled by a weight channel, and the (bs, bf * B) bin mask — and
+walks (feature-block, sample-block) with samples innermost.  Samples
+ride the 128-wide lane axis of every operand — xb as (F, N), node as
+(1, N), w as (K, N) — so no block is a 1-D or lane-sparse slice.  Each
+step builds two one-hot operands on the VPU, the (num_nodes, bs) node
+mask scaled by a weight channel and the (bf * B, bs) bin mask, and
 contracts them over the sample axis with one MXU matmul per channel,
 accumulating into the revisited output block (``pl.when`` zero-init on
-the first sample step).  Rows padded to the sample-block multiple ride
-at w == 0, so they contribute exact zeros — the same invariant the
+the first sample step).  The bin mask needs row f*B + b to read
+feature f: an (bf * B, bf) 0/1 expansion matmul replicates each
+feature row B times, which keeps every intermediate 2-D and
+tile-aligned.  Rows padded to the sample-block multiple ride at
+w == 0, so they contribute exact zeros — the same invariant the
 stacked (teacher-axis) fits rely on for padding rows.
 """
 from __future__ import annotations
@@ -28,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_HI = jax.lax.Precision.HIGHEST
+
 
 def _kernel(xb_ref, node_ref, w_ref, out_ref, *, K, num_nodes, num_bins,
             bs, bf):
@@ -37,21 +44,27 @@ def _kernel(xb_ref, node_ref, w_ref, out_ref, *, K, num_nodes, num_bins,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    node_b = node_ref[...]                                      # (bs,)
-    n_iota = jax.lax.broadcasted_iota(jnp.int32, (bs, num_nodes), 1)
-    onehot_n = (node_b[:, None] == n_iota).astype(jnp.float32)  # (bs, n)
+    rows = bf * num_bins
+    n_iota = jax.lax.broadcasted_iota(jnp.int32, (num_nodes, bs), 0)
+    onehot_n = (node_ref[...] == n_iota).astype(jnp.float32)    # (n, bs)
 
-    xb_b = xb_ref[...]                                          # (bs, bf)
-    b_iota = jax.lax.broadcasted_iota(jnp.int32, (bs, bf, num_bins), 2)
-    onehot_b = (xb_b[:, :, None] == b_iota).astype(jnp.float32)
-    onehot_b = onehot_b.reshape(bs, bf * num_bins)
+    # expand[f*B + b, f'] = [f == f']; tgt[f*B + b] = b
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (rows, bf), 0)
+    lo = jax.lax.broadcasted_iota(jnp.int32, (rows, bf), 1) * num_bins
+    hit = (r_iota >= lo) & (r_iota < lo + num_bins)
+    expand = hit.astype(jnp.float32)
+    tgt = jnp.sum(jnp.where(hit, r_iota - lo, 0), axis=1, keepdims=True)
+    # bin ids are small integers: exact through the f32 matmul
+    xrep = jax.lax.dot(expand, xb_ref[...].astype(jnp.float32),
+                       precision=_HI)                           # (rows, bs)
+    onehot_b = (xrep == tgt.astype(jnp.float32)).astype(jnp.float32)
 
-    w_b = w_ref[...]                                            # (bs, K)
+    w_b = w_ref[...]                                            # (K, bs)
     contrib = []
     for k in range(K):                       # static channel unroll
-        nck = onehot_n * w_b[:, k][:, None]                     # (bs, n)
+        nck = onehot_n * w_b[k:k + 1, :]                        # (n, bs)
         contrib.append(jax.lax.dot_general(
-            nck, onehot_b, (((0,), (0,)), ((), ())),
+            nck, onehot_b, (((1,), (1,)), ((), ())), precision=_HI,
             preferred_element_type=jnp.float32))   # (n, bf*B) on the MXU
     out_ref[...] += jnp.concatenate(contrib, axis=0)
 
@@ -62,6 +75,10 @@ def tree_hist(xb, node, w, *, num_nodes, num_bins, block_s=512,
               block_f=None, interpret=False):
     """xb: (N, F) int32 bins; node: (N,) int32; w: (K, N) f32 channel
     weights.  Returns (K, num_nodes, F, num_bins) f32 weighted counts.
+
+    ``block_s`` must be a multiple of 128 (or cover N); a feature block
+    smaller than F must be a multiple of 8 — the TPU tiling rules for
+    the sample (lane) and feature (sublane) axes.
     """
     N, F = xb.shape
     K = w.shape[0]
@@ -83,15 +100,15 @@ def tree_hist(xb, node, w, *, num_nodes, num_bins, block_s=512,
         kern,
         grid=(nf, ns),
         in_specs=[
-            pl.BlockSpec((bs, bf), lambda i_f, i_s: (i_s, i_f)),
-            pl.BlockSpec((bs,), lambda i_f, i_s: (i_s,)),
-            pl.BlockSpec((bs, K), lambda i_f, i_s: (i_s, 0)),
+            pl.BlockSpec((bf, bs), lambda i_f, i_s: (i_f, i_s)),
+            pl.BlockSpec((1, bs), lambda i_f, i_s: (0, i_s)),
+            pl.BlockSpec((K, bs), lambda i_f, i_s: (0, i_s)),
         ],
         out_specs=pl.BlockSpec((K * num_nodes, bf * num_bins),
                                lambda i_f, i_s: (0, i_f)),
         out_shape=jax.ShapeDtypeStruct(
             (K * num_nodes, nf * bf * num_bins), jnp.float32),
         interpret=interpret,
-    )(xb, node, w.T)
+    )(xb.T, node[None, :], w.astype(jnp.float32))
     out = out.reshape(K, num_nodes, nf * bf, num_bins)
     return out[:, :, :F]

@@ -10,8 +10,14 @@ fits on chip.  TPU-native reformulation: the grid walks (query-block,
 class-block) with the class axis innermost; each step histogram-counts the
 M teacher votes that fall inside the current class block (rank-1 compares
 on the VPU, no HBM histogram), adds the Laplace noise block, and folds the
-block's top-2 into running (best, second, argbest) VMEM accumulators.
+block's top-2 into running (best, second, argbest) accumulators: the
+output blocks themselves, revisited along the class axis.
 Output is O(T), not O(T*U).
+
+Queries ride the 128-wide lane axis throughout: the class block is
+(bu, bt) with classes on sublanes, noise arrives transposed as (U, T),
+and every per-query statistic is a lane-dense (1, T) row — so no block
+is 1-D or lane-sparse, whatever the class count.
 """
 from __future__ import annotations
 
@@ -20,21 +26,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _block_top2(scores, bt, bu):
-    """(m1 (bt,1), argmax (bt,), m2 (bt,1)) of one class block.  Masks
-    only the argmax POSITION (not every equal value), so exact ties
-    yield m2 == m1 — matching the xla path's one_hot masking and the
-    top_k semantics the Lemma-7 gap needs on clean integer counts."""
-    m1 = jnp.max(scores, axis=1, keepdims=True)                  # (bt,1)
-    i1 = jnp.argmax(scores, axis=1).astype(jnp.int32)            # (bt,)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (bt, bu), 1)
-    masked = jnp.where(pos == i1[:, None], NEG_INF, scores)
-    m2 = jnp.max(masked, axis=1, keepdims=True)
+    """(m1 (1,bt), argmax (1,bt), m2 (1,bt)) of one (bu, bt) class
+    block.  The argmax is the FIRST maximal class (a min over matching
+    positions), and only that POSITION is masked for m2 (not every
+    equal value), so exact ties yield m2 == m1 — matching the xla
+    path's one_hot masking and the top_k semantics the Lemma-7 gap
+    needs on clean integer counts."""
+    m1 = jnp.max(scores, axis=0, keepdims=True)                  # (1,bt)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bu, bt), 0)
+    i1 = jnp.min(jnp.where(scores == m1, pos, bu), axis=0, keepdims=True)
+    masked = jnp.where(pos == i1, NEG_INF, scores)
+    m2 = jnp.max(masked, axis=0, keepdims=True)
     return m1, i1, m2
 
 
@@ -50,53 +57,41 @@ def _fold_top2(best, second, m1, m2):
 
 
 def _kernel(preds_ref, noise_ref, label_ref, top1_ref, top2_ref,
-            clean1_ref, clean2_ref, best_ref, second_ref, argbest_ref,
-            cbest_ref, csecond_ref, *, M, bt, bu, nu):
+            clean1_ref, clean2_ref, *, M, bt, bu):
     iu = pl.program_id(1)
 
     @pl.when(iu == 0)
     def _init():
-        best_ref[...] = jnp.full_like(best_ref, NEG_INF)
-        second_ref[...] = jnp.full_like(second_ref, NEG_INF)
-        argbest_ref[...] = jnp.zeros_like(argbest_ref)
-        cbest_ref[...] = jnp.full_like(cbest_ref, NEG_INF)
-        csecond_ref[...] = jnp.full_like(csecond_ref, NEG_INF)
+        for ref in (top1_ref, top2_ref, clean1_ref, clean2_ref):
+            ref[...] = jnp.full_like(ref, NEG_INF)
+        label_ref[...] = jnp.zeros_like(label_ref)
 
     class_base = iu * bu
-    ids = class_base + jax.lax.broadcasted_iota(jnp.int32, (bt, bu), 1)
+    ids = class_base + jax.lax.broadcasted_iota(jnp.int32, (bu, bt), 0)
 
     def count_one(m, counts):
-        p = preds_ref[m, :]                       # (bt,)
-        return counts + (p[:, None] == ids).astype(jnp.float32)
+        p = preds_ref[pl.ds(m, 1), :]             # (1, bt)
+        return counts + (p == ids).astype(jnp.float32)
 
     counts = jax.lax.fori_loop(
-        0, M, count_one, jnp.zeros((bt, bu), jnp.float32))
+        0, M, count_one, jnp.zeros((bu, bt), jnp.float32))
 
     # clean top-2 (pre-noise): the privacy accountant's gap input, from
     # the SAME histogram the noisy argmax consumes
     cm1, _, cm2 = _block_top2(counts, bt, bu)
-    _, cbest, csecond = _fold_top2(cbest_ref[...], csecond_ref[...],
+    _, cbest, csecond = _fold_top2(clean1_ref[...], clean2_ref[...],
                                    cm1, cm2)
-    cbest_ref[...] = cbest
-    csecond_ref[...] = csecond
+    clean1_ref[...] = cbest
+    clean2_ref[...] = csecond
 
     # noisy top-2 of this class block
     scores = counts + noise_ref[...].astype(jnp.float32)
     m1, i1, m2 = _block_top2(scores, bt, bu)
-    take, new_best, new_second = _fold_top2(best_ref[...], second_ref[...],
+    take, new_best, new_second = _fold_top2(top1_ref[...], top2_ref[...],
                                             m1, m2)
-    argbest_ref[...] = jnp.where(
-        take[:, 0], class_base + i1, argbest_ref[...])
-    best_ref[...] = new_best
-    second_ref[...] = new_second
-
-    @pl.when(iu == nu - 1)
-    def _final():
-        label_ref[...] = argbest_ref[...]
-        top1_ref[...] = best_ref[...][:, 0]
-        top2_ref[...] = second_ref[...][:, 0]
-        clean1_ref[...] = cbest_ref[...][:, 0]
-        clean2_ref[...] = csecond_ref[...][:, 0]
+    label_ref[...] = jnp.where(take, class_base + i1, label_ref[...])
+    top1_ref[...] = new_best
+    top2_ref[...] = new_second
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -115,29 +110,18 @@ def vote_aggregate(preds, noise, *, num_classes, block_t=128, block_u=512,
     assert T % bt == 0 and U % bu == 0, (T, U, bt, bu)
     nt, nu = T // bt, U // bu
 
-    kern = functools.partial(_kernel, M=M, bt=bt, bu=bu, nu=nu)
-    return pl.pallas_call(
+    kern = functools.partial(_kernel, M=M, bt=bt, bu=bu)
+    row = pl.BlockSpec((1, bt), lambda it, iu: (0, it))
+    outs = pl.pallas_call(
         kern,
         grid=(nt, nu),
         in_specs=[
             pl.BlockSpec((M, bt), lambda it, iu: (0, it)),
-            pl.BlockSpec((bt, bu), lambda it, iu: (it, iu)),
+            pl.BlockSpec((bu, bt), lambda it, iu: (iu, it)),
         ],
-        out_specs=[pl.BlockSpec((bt,), lambda it, iu: (it,))
-                   for _ in range(5)],
-        out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bt, 1), jnp.float32),   # best
-            pltpu.VMEM((bt, 1), jnp.float32),   # second
-            pltpu.VMEM((bt,), jnp.int32),       # argbest
-            pltpu.VMEM((bt, 1), jnp.float32),   # clean best
-            pltpu.VMEM((bt, 1), jnp.float32),   # clean second
-        ],
+        out_specs=[row] * 5,
+        out_shape=[jax.ShapeDtypeStruct((1, T), jnp.int32)]
+        + [jax.ShapeDtypeStruct((1, T), jnp.float32)] * 4,
         interpret=interpret,
-    )(preds, noise)
+    )(preds, noise.T)
+    return tuple(o[0] for o in outs)

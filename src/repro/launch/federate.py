@@ -39,11 +39,16 @@ disjoint column slice (core.partition.vertical_split), trains a
 feature-masked learner, and votes in the shared example domain — see
 examples/vertical_fedkt.py for the annotated walkthrough.
 
-Demo (two shells):
+Demo (two shells, CPU hosts):
   PYTHONPATH=src python -m repro.launch.federate coordinator \
       --parties 4 --port 7733 --deadline-s 120 --min-parties 3
   for i in 0 1 2 3; do PYTHONPATH=src python -m repro.launch.federate \
       party --party-id $i --parties 4 --port 7733 & done
+
+One process per chip: a TPU belongs to the one process that first
+touches JAX, so the roles above can share a chip host only inside the
+``local`` role (one process, parties on threads).  Run the coordinator
+and each party role on hosts of their own.
 
 See docs/federation.md for the deployment guide (timeout/quorum knobs,
 dropout accounting, wire-byte pricing).
@@ -63,6 +68,7 @@ from repro.federation import (FedKTSession, PartyBinding, SocketTransport,
                               party_starting_keys, query_budget,
                               run_party_client)
 from repro.federation.bindings import registered_learner_kinds
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.smallnets import MLP
 
 LEARNER_KINDS = ("nn", "rf", "gbdt")
@@ -167,6 +173,7 @@ def _report(result) -> None:
         "epsilon": result.epsilon,
         "arrived": len(sock.get("arrived", [])),
         "dropped_parties": result.meta.get("dropped_parties", []),
+        "failed": sock.get("failed", {}),
         "wire_bytes": result.meta["wire_bytes"],
         "seconds": result.meta["seconds"],
     }
@@ -192,7 +199,7 @@ def _chaos_plan(args):
     return FaultPlan.random(args.chaos_seed, 3 * args.parties)
 
 
-def run_local(args) -> None:
+def run_local(args):
     transport = SocketTransport(parallelism=args.parallelism,
                                 port=args.port,
                                 deadline_s=args.deadline_s,
@@ -202,9 +209,10 @@ def run_local(args) -> None:
                                 chaos_plan=_chaos_plan(args))
     result = build_session(args, transport).run(verbose=args.verbose)
     _report(result)
+    return result
 
 
-def run_coordinator(args) -> None:
+def run_coordinator(args):
     transport = SocketTransport(host=args.host, port=args.port,
                                 spawn=False,
                                 deadline_s=args.deadline_s,
@@ -220,9 +228,10 @@ def run_coordinator(args) -> None:
              if args.journal else ""))
     result = build_session(args, transport).run(verbose=args.verbose)
     _report(result)
+    return result
 
 
-def run_party(args) -> None:
+def run_party(args) -> int:
     session = build_session(args, "inprocess")   # setup only, never run
     keys, _ = party_starting_keys(session.parties, args.seed)
     party = session.parties[args.party_id]
@@ -235,9 +244,10 @@ def run_party(args) -> None:
     kind = session.bindings[args.party_id].kind
     print(f"party {args.party_id} ({kind}): update delivered to "
           f"{args.host}:{args.port} ({nbytes} framed bytes)")
+    return nbytes
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="one FedKT round over TCP sockets")
     ap.add_argument("role", choices=["local", "coordinator", "party"])
@@ -306,9 +316,17 @@ def main():
     ap.add_argument("--party-id", type=int, default=0,
                     help="party role: which silo this process is")
     ap.add_argument("--verbose", action="store_true")
-    args = ap.parse_args()
-    {"local": run_local, "coordinator": run_coordinator,
-     "party": run_party}[args.role](args)
+    return ap
+
+
+def main(argv=None):
+    """Runs one role.  ``argv`` defaults to the command line; returns
+    the role's outcome (the ``RoundResult`` of local/coordinator, the
+    delivered framed bytes of a party)."""
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    return {"local": run_local, "coordinator": run_coordinator,
+            "party": run_party}[args.role](args)
 
 
 if __name__ == "__main__":

@@ -15,29 +15,36 @@ serve_bench measures):
   PYTHONPATH=src python -m repro.launch.serve --arch gemma2-27b \
       --smoke --concurrent 16 --arrival 4 --slots 4
 
-Deployment hygiene (SNIPPETS.md): allocator and logging knobs must be
-in the environment BEFORE jax/XLA initialise, so this module imports
-NOTHING heavy at module scope — ``main`` sets the env from flags and
-only then imports the stack.
+Environment: allocator and logging knobs must be in the environment
+BEFORE XLA initialises its backends, so ``main`` sets them from flags
+first and only then imports the model stack.  What it sets:
 
   --host-devices N   sets XLA_FLAGS=--xla_force_host_platform_device_count
                      (multi-device CPU topology for mesh dry-runs)
   TF_CPP_MIN_LOG_LEVEL defaults to 2 (mute absl chatter)
   TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD defaults to 2**38 (mute large-
                      alloc warnings for weight-sized host buffers)
-  LD_PRELOAD=/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4 cannot be set
-                     from inside a running process — export it in the
-                     service unit; host weight staging is measurably
-                     faster under tcmalloc.
+  the persistent compilation cache directory (launch/compile_cache.py)
+
+Weights are served in the model's compute dtype (``cfg.dtype``, bf16
+for the published configs — the dtype their checkpoints ship in) and
+are created by one jitted init, so no float32 copy of the whole stack
+ever exists: phi4-mini's 3.84 B parameters take 7.7 GB in bf16 and
+would take 15.3 GB of a 16 GiB chip in float32.  Every matmul casts its
+weight to the compute dtype anyway, so the served tokens equal those
+of float32-held weights.
 
 Models the engine refuses (recurrent state, encoder-decoder) fall back
-to the fixed-batch serial path automatically.
+to the fixed-batch serial path; ``main`` prints the reason and returns
+``path == "serial"``, so a caller that needs the engine can tell.
 """
 from __future__ import annotations
 
 import argparse
 import os
 import time
+from dataclasses import dataclass, field
+from typing import Any, Dict, List, Optional
 
 
 def __getattr__(name):
@@ -46,6 +53,24 @@ def __getattr__(name):
         from repro import serving
         return getattr(serving, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+@dataclass
+class ServeRun:
+    """What one ``main`` call served.  ``path`` is "engine" (continuous
+    batching) or "serial" (the fixed-batch fallback, ``reason`` says
+    why); ``results`` holds the engine's StreamResults or the serial
+    path's stats.  Times are host seconds: ``init_s`` builds the
+    weights, ``warmup_s`` compiles every bucket, ``wall_s`` serves."""
+    path: str
+    reason: str
+    results: Any
+    model: Any
+    params: Any
+    prompts: List[Any]
+    engine: Any = None
+    warm_counts: Optional[Dict[str, int]] = None
+    times: Dict[str, float] = field(default_factory=dict)
 
 
 def _apply_env(args):
@@ -124,16 +149,26 @@ def main(argv=None):
 
     from repro import checkpoint as ckpt_lib
     from repro.configs import ARCH_IDS, get_config, get_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import Model
     from repro.serving import Engine, serve_batch
 
+    enable_compile_cache()
     if args.arch not in ARCH_IDS:
         ap.error(f"unknown arch {args.arch!r} (choose from {ARCH_IDS})")
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    cfg = cfg.replace(param_dtype=cfg.dtype)
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    times = {}
+    t0 = time.perf_counter()
     if args.checkpoint:
-        params = ckpt_lib.restore(args.checkpoint, params)
+        like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda a, t: a.astype(t.dtype),
+                              ckpt_lib.restore(args.checkpoint, like), like)
+    else:
+        params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    jax.block_until_ready(params)
+    times["init_s"] = time.perf_counter() - t0
 
     rng = np.random.default_rng(args.seed)
     lens = rng.integers(1, args.prompt_len + 1, args.concurrent)
@@ -147,7 +182,7 @@ def main(argv=None):
                      cache_len=args.cache_len, eos_id=args.eos)
     except NotImplementedError as why:
         # recurrent / encoder-decoder configs: fixed-batch fallback
-        print(f"serial fixed-batch path ({why})")
+        print(f"FALLBACK: serial fixed-batch path, not the engine ({why})")
         P = args.prompt_len
         batch = np.stack([np.resize(p, P) for p in prompts])
         extra = {}
@@ -160,13 +195,16 @@ def main(argv=None):
                                     args.max_tokens, extra=extra,
                                     eos_id=args.eos)
         print("generated:", tokens[:, :8], "...")
-        return stats
+        return ServeRun("serial", str(why), stats, model, params, prompts,
+                        times=times)
 
-    eng.warmup(buckets=[p.shape[0] for p in prompts])
+    t0 = time.perf_counter()
+    warm = eng.warmup(buckets=[p.shape[0] for p in prompts])
+    times["warmup_s"] = time.perf_counter() - t0
     t0 = eng.clock()
     results = _drive(eng, prompts, args.max_tokens, args.arrival,
                      args.seed)
-    wall = eng.clock() - t0
+    wall = times["wall_s"] = eng.clock() - t0
     toks = sum(r.num_tokens for r in results)
     lats = [t for r in results for t in r.timing["token_latencies"]]
     print(f"{len(results)} streams, {toks} tokens in {wall:.2f}s "
@@ -176,7 +214,8 @@ def main(argv=None):
           f"compile counts {eng.compile_counts()}")
     for r in results[:4]:
         print(f"  req {r.rid} plen {r.prompt_len}: {r.tokens[:8]} ...")
-    return results
+    return ServeRun("engine", "", results, model, params, prompts,
+                    engine=eng, warm_counts=warm, times=times)
 
 
 if __name__ == "__main__":

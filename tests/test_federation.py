@@ -37,10 +37,14 @@ def _tree_equal(a, b):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
-# Recorded from the legacy ``run_fedkt`` entry point (deleted this PR)
-# on the exact config below — the loop engine reproduced it bit-for-bit
-# through PR 1/2/3, including the transport-layer codec round-trip.
-LEGACY_ACCURACY = 0.50390625
+# First recorded from the legacy ``run_fedkt`` entry point (since
+# deleted) on the exact config below; the loop engine reproduced it
+# bit-for-bit, including the transport-layer codec round-trip.
+# Re-recorded under JAX 0.9.0's default PRNG: the old accuracy,
+# 0.50390625, came from the non-partitionable threefry stream, which
+# this JAX no longer uses by default (the old number still comes back
+# with JAX_THREEFRY_PARTITIONABLE=0).  Epsilon did not move.
+LEGACY_ACCURACY = 0.63671875
 LEGACY_EPSILON = 13.436462732485094
 
 

@@ -540,3 +540,14 @@ def test_thread_cleanup_on_party_failure(data, learner):
                                party_indices=shards, transport=transport)
         with pytest.raises(IndexError):
             session.run()
+
+
+def test_subprocess_transport_refuses_a_tpu_parent(monkeypatch):
+    """One process per chip: on a TPU backend the parent holds the chip,
+    so spawned parties could not reach it.  The transport refuses up
+    front, naming why; on a CPU backend it builds as before."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        get_transport("subprocess")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert get_transport("subprocess").name == "subprocess"

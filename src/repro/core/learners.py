@@ -24,12 +24,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import trees as T
 from repro.optim import adamw
 
 
 def _pow2_bucket(n, min_size=32):
     return max(min_size, 1 << (n - 1).bit_length())
+
+
+def shared_bucket(Xs):
+    """The one pow2 bucket a stacked fit pads all of its members to:
+    the largest member's own."""
+    return max(_pow2_bucket(len(X)) for X in Xs)
+
+
+def _pad_span(Xs, bucket):
+    """``fedkt.pad`` over the padding of ``Xs`` to ``bucket`` rows each
+    (with the padded arrays' put to the device): the true rows against
+    the rows the fit runs over."""
+    return obs.span("fedkt.pad", rows=sum(len(X) for X in Xs),
+                    padded_rows=len(Xs) * bucket)
 
 
 def _mask_cols(X, mask):
@@ -101,8 +116,10 @@ class NNLearner:
         return jax.vmap(self._fit_body)(keys, X, y, mask)
 
     def fit(self, key, X, y):
-        Xp, yp, mask = _pad_pow2(_mask_cols(X, self.feature_mask),
-                                 np.asarray(y))
+        X = _mask_cols(X, self.feature_mask)
+        bucket = _pow2_bucket(len(X))
+        with _pad_span([X], bucket):
+            Xp, yp, mask = _pad_pow2(X, np.asarray(y), bucket=bucket)
         return self._fit(key, Xp, yp, mask)
 
     def fit_stacked(self, keys, Xs, ys):
@@ -111,10 +128,11 @@ class NNLearner:
         per-row masks keep each model's sampling distribution on its own
         examples, so a model trained here matches its serial ``fit``
         whenever its individual bucket equals the shared one."""
-        bucket = max(_pow2_bucket(len(X)) for X in Xs)
-        padded = [_pad_pow2(_mask_cols(X, self.feature_mask),
-                            np.asarray(y), bucket=bucket)
-                  for X, y in zip(Xs, ys)]
+        Xs = [_mask_cols(X, self.feature_mask) for X in Xs]
+        bucket = shared_bucket(Xs)
+        with _pad_span(Xs, bucket):
+            padded = [_pad_pow2(X, np.asarray(y), bucket=bucket)
+                      for X, y in zip(Xs, ys)]
         Xp, yp, mask = (jnp.stack([p[i] for p in padded])
                         for i in range(3))
         return self._fit_stacked(jnp.asarray(keys), Xp, yp, mask)
@@ -168,20 +186,22 @@ class RFLearner:
         so the stacked states are bit-identical to the serial loop
         regardless of bucket size (histograms ignore w == 0 rows)."""
         rf = self._rf()
-        bucket = max(_pow2_bucket(len(X)) for X in Xs)
-        edges, Xp, yp, wp, fm = [], [], [], [], []
-        for kk, X, y in zip(keys, Xs, ys):
-            X = _mask_cols(X, self.feature_mask).astype(np.float32)
-            edges.append(T.make_bins(X))
-            w_i, fm_i = rf.bootstrap(kk, len(X), X.shape[1])
-            w_pad = np.zeros((self.num_trees, bucket), np.float32)
-            w_pad[:, :len(X)] = np.asarray(w_i)
-            Xi, yi, _ = _pad_pow2(X, np.asarray(y), bucket=bucket)
-            Xp.append(Xi), yp.append(yi), wp.append(w_pad), fm.append(fm_i)
-        edges = jnp.asarray(np.stack(edges))
+        Xs = [_mask_cols(X, self.feature_mask).astype(np.float32)
+              for X in Xs]
+        bucket = shared_bucket(Xs)
+        edges = jnp.asarray(np.stack([T.make_bins(X) for X in Xs]))
+        w, fm = zip(*(rf.bootstrap(kk, len(X), X.shape[1])
+                      for kk, X in zip(keys, Xs)))
+        w = [np.asarray(w_i) for w_i in w]
+        with _pad_span(Xs, bucket):
+            wp = np.zeros((len(Xs), self.num_trees, bucket), np.float32)
+            for wp_i, w_i in zip(wp, w):
+                wp_i[:, :w_i.shape[1]] = w_i
+            Xp, yp, _ = zip(*(_pad_pow2(X, np.asarray(y), bucket=bucket)
+                              for X, y in zip(Xs, ys)))
         forest = T.fit_forest_stacked(
             jnp.stack(Xp), edges, jnp.stack(yp),
-            jnp.asarray(np.stack(wp)), jnp.stack(fm),
+            jnp.asarray(wp), jnp.stack(fm),
             depth=self.depth, num_classes=self.num_classes,
             impl=self.impl)
         return (forest, edges)
@@ -223,14 +243,13 @@ class GBDTLearner:
         rows carry zero g/h weight, so stacked == serial bit-for-bit
         (see trees.fit_gbdt)."""
         gb = self._gb()
-        bucket = max(_pow2_bucket(len(X)) for X in Xs)
-        edges, Xp, yp, wp = [], [], [], []
-        for X, y in zip(Xs, ys):
-            X = _mask_cols(X, self.feature_mask).astype(np.float32)
-            edges.append(T.make_bins(X))
-            Xi, yi, mi = _pad_pow2(X, np.asarray(y), bucket=bucket)
-            Xp.append(Xi), yp.append(yi), wp.append(mi)
-        edges = jnp.asarray(np.stack(edges))
+        Xs = [_mask_cols(X, self.feature_mask).astype(np.float32)
+              for X in Xs]
+        bucket = shared_bucket(Xs)
+        edges = jnp.asarray(np.stack([T.make_bins(X) for X in Xs]))
+        with _pad_span(Xs, bucket):
+            Xp, yp, wp = zip(*(_pad_pow2(X, np.asarray(y), bucket=bucket)
+                               for X, y in zip(Xs, ys)))
         trees = T.fit_gbdt_stacked(
             jnp.stack(Xp), edges, jnp.stack(yp), jnp.stack(wp),
             gb.learning_rate, num_rounds=self.num_rounds, depth=self.depth,

@@ -80,11 +80,13 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional,
 
 import numpy as np
 
+from repro import obs
 from repro.federation.codec import (CorruptFrameError, TruncatedFrameError,
                                     VersionMismatchError, encode_update)
 from repro.federation.journal import RoundJournal
 from repro.federation.messages import PartyUpdate
-from repro.federation.transport import TransportBase, _decode_annotated
+from repro.federation.transport import (TransportBase, _decode_annotated,
+                                        _silo_turn)
 
 _LEN = struct.Struct("<I")
 MAX_FRAME_BYTES = 1 << 31        # sanity bound on a length prefix
@@ -203,10 +205,14 @@ def run_party_client(host: str, port: int, party, key, X_public,
     send-until-ACK safe: if the coordinator journaled the update but
     the ACK was lost, the retransmit is re-ACKed, never double-folded.
     See launch/federate.py for the CLI wrapper."""
-    upd, _ = party.local_round(key, X_public, num_queries, engine)
-    payload = encode_update(upd)
-    send_update_frame(host, port, payload, retries=retries,
-                      backoff_s=backoff_s, io_timeout_s=io_timeout_s)
+    with _silo_turn(party):
+        upd, _ = party.local_round(key, X_public, num_queries, engine)
+        with obs.span("fedkt.encode"):
+            payload = encode_update(upd)
+        with obs.span("fedkt.send", bytes=len(payload)):
+            send_update_frame(host, port, payload, retries=retries,
+                              backoff_s=backoff_s,
+                              io_timeout_s=io_timeout_s)
     return len(payload)
 
 
@@ -343,7 +349,8 @@ class Coordinator:
                     upd = None
                 else:
                     payload = await reader.readexactly(nbytes)
-                    reply, upd = self._admit(payload)
+                    with obs.span("fedkt.decode", bytes=len(payload)):
+                        reply, upd = self._admit(payload)
             except asyncio.IncompleteReadError as err:
                 # the frame never finished arriving (killed connection,
                 # half-shipped bytes): retryable by definition
@@ -422,7 +429,9 @@ class Coordinator:
                 if self.journal is not None:
                     self.journal.close()
                 self._loop.close()
-        self._thread = threading.Thread(target=runner, daemon=True,
+        # the loop thread's spans carry the round that started it
+        self._thread = threading.Thread(target=obs.carry(runner),
+                                        daemon=True,
                                         name="fedkt-coordinator")
         self._thread.start()
         if not self._started.wait(timeout=30.0):
@@ -584,7 +593,8 @@ class SocketTransport(TransportBase):
                     if int(party.party_id) in replayed:
                         continue         # its update already folded
                     fut = pool.submit(
-                        _ship_round, party, key, Xpub, num_queries,
+                        obs.carry(_ship_round), party, key, Xpub,
+                        num_queries,
                         engine, self.host, deliver_port,
                         self.connect_retries, self.backoff_s,
                         self.io_timeout_s)

@@ -14,6 +14,7 @@ from typing import Any, List
 import jax
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedKTConfig
 from repro.core.partition import subsets_of_partition
 from repro.federation.bindings import learner_kind
@@ -116,25 +117,32 @@ class Party:
             self._key_schedule(key, s, t)
         datasets = [(self.X[sub], self.y[sub])
                     for j in range(s) for sub in plan[j]]
-        bank = engine.fit_teachers(teacher_keys, self.learner, datasets)
+        # the fits are dispatched, not awaited: their device time shows
+        # in the vote's wait for its labels
+        with obs.span("fedkt.teacher_fit", teachers=s * t):
+            bank = engine.fit_teachers(teacher_keys, self.learner,
+                                       datasets)
 
         labelsets: List[np.ndarray] = []
         gaps: List[np.ndarray] = []
-        for j in range(s):
-            bank_j = engine.slice_bank(bank, j * t, (j + 1) * t)
-            # HOW the queries get labeled is the engine's concern
-            # (serial predicts + histogram vote, or the LM path's fused
-            # label step); the protocol only needs labels + clean gaps
-            labels, gap = engine.label_queries(
-                self.learner, bank_j, Xq, u, gamma=gamma,
-                key=vote_keys[j])
-            gaps.append(np.asarray(gap))
-            labelsets.append(np.asarray(labels))
+        with obs.span("fedkt.party_vote", queries=len(Xq)):
+            for j in range(s):
+                bank_j = engine.slice_bank(bank, j * t, (j + 1) * t)
+                # HOW the queries get labeled is the engine's concern
+                # (serial predicts + histogram vote, or the LM path's
+                # fused label step); the protocol only needs labels +
+                # clean gaps
+                labels, gap = engine.label_queries(
+                    self.learner, bank_j, Xq, u, gamma=gamma,
+                    key=vote_keys[j])
+                gaps.append(np.asarray(gap))
+                labelsets.append(np.asarray(labels))
         # all s students vote on the same Xq, so the engine may train
         # them as ONE stacked fit; student_keys is the precomputed legacy
         # schedule, so batching never changes a student's seed
-        students: List[Any] = engine.fit_students(
-            student_keys, self.student_learner, Xq, labelsets)
+        with obs.span("fedkt.student_fit", students=s):
+            students: List[Any] = engine.fit_students(
+                student_keys, self.student_learner, Xq, labelsets)
 
         update = PartyUpdate(party_id=self.party_id,
                              student_states=students,

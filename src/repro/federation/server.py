@@ -18,6 +18,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedKTConfig
 from repro.federation.aggregate import StreamingVoteAggregate
 from repro.federation.engines import Engine, LoopEngine
@@ -72,19 +73,23 @@ class Server:
         final learner itself votes in (agg.primary_domain).
 
         Returns (final_state, primary VoteResult, {domain.ident ->
-        VoteResult}, key).  With one domain this is split-for-split the
-        legacy ``finalize`` — one split for vote noise, one for the
-        final fit — so every existing single-domain round stays
-        bit-identical."""
-        votes = {}
-        for dom in agg.domains():
+        VoteResult}, key), once ``final_state`` is ready on the device.
+        With one domain this is split-for-split the legacy ``finalize``
+        — one split for vote noise, one for the final fit — so every
+        existing single-domain round stays bit-identical."""
+        with obs.span("fedkt.finalize", queries=len(agg.Xq)):
+            votes = {}
+            for dom in agg.domains():
+                key, kk = jax.random.split(key)
+                votes[dom.ident] = agg.finalize_domain(dom, kk)
+            primary = agg.primary_domain(self.final_learner)
+            vote = votes[primary.ident]
             key, kk = jax.random.split(key)
-            votes[dom.ident] = agg.finalize_domain(dom, kk)
-        primary = agg.primary_domain(self.final_learner)
-        vote = votes[primary.ident]
-        key, kk = jax.random.split(key)
-        final_state = self.final_learner.fit(kk, agg.Xq,
-                                             np.asarray(vote.labels))
+            final_state = self.final_learner.fit(kk, agg.Xq,
+                                                 np.asarray(vote.labels))
+            # the span (and the session's server clock) ends with the
+            # final student on the device, not with its dispatch
+            jax.block_until_ready(final_state)
         return final_state, vote, votes, key
 
     def aggregate(self, key, updates: Sequence[PartyUpdate], X_public,

@@ -54,6 +54,7 @@ from typing import Any, Dict
 import jax
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FedKTConfig
 from repro.core.learners import accuracy
 from repro.core.partition import dirichlet_partition
@@ -153,6 +154,10 @@ class FedKTSession:
                                                      len(data["X_public"]))
 
     def run(self, verbose: bool = False) -> RoundResult:
+        with obs.round_scope(silos=len(self.parties)):
+            return self._run(verbose)
+
+    def _run(self, verbose: bool) -> RoundResult:
         cfg = self.cfg
         Xpub = self.data["X_public"]
         party_keys, key = party_starting_keys(self.parties, cfg.seed)
@@ -162,13 +167,14 @@ class FedKTSession:
         streaming = getattr(self.transport, "streams", False)
 
         def fold(upd):
-            agg.add(upd)
+            with obs.span("fedkt.fold", silo=upd.party_id):
+                agg.add(upd)
             if verbose:
                 print(f"party {upd.party_id}: {upd.num_examples} "
                       f"examples, {upd.meta['num_teachers']} teachers "
                       f"trained, {upd.meta['encoded_bytes']} wire bytes")
 
-        t0 = time.time()
+        t0 = time.perf_counter()
         # engine=None: every party runs under its OWN bound engine (the
         # heterogeneous contract; in the homogeneous shorthand all
         # bindings share the session engine, so nothing changes)
@@ -180,17 +186,18 @@ class FedKTSession:
                     self.parties, party_keys, Xpub, self.tq_party,
                     None):
                 fold(upd)
-            t_parties = time.time() - t0
-            t0 = time.time()
+            t_parties = time.perf_counter() - t0
+            t0 = time.perf_counter()
         else:
             updates = self.transport.run_round(
                 self.parties, party_keys, Xpub, self.tq_party, None)
-            t_parties = time.time() - t0
-            t0 = time.time()
+            t_parties = time.perf_counter() - t0
+            t0 = time.perf_counter()
             for upd in updates:
                 fold(upd)
+        # returns once the final student is ready on the device
         final_state, vote, votes, key = self.server.finalize_all(key, agg)
-        t_server = time.time() - t0
+        t_server = time.perf_counter() - t0
 
         acc = accuracy(self.final_learner, final_state,
                        self.data["X_test"], self.data["y_test"])

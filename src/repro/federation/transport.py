@@ -42,6 +42,8 @@ from typing import Any, List, Optional, Protocol, Sequence
 
 import numpy as np
 
+from repro import obs
+from repro.federation.bindings import learner_kind
 from repro.federation.codec import decode_update, encode_update
 from repro.federation.messages import PartyUpdate
 
@@ -92,9 +94,18 @@ def _decode_annotated(buf: bytes) -> PartyUpdate:
     return upd
 
 
+def _silo_turn(party):
+    """``fedkt.silo`` over one party's whole turn: its local round, the
+    encode, and the send where the update travels over a socket."""
+    return obs.silo_scope(party.party_id, learner=learner_kind(party.learner),
+                          rows=party.num_examples)
+
+
 def _encoded_round(party, key, X_public, num_queries, engine) -> bytes:
-    upd, _ = party.local_round(key, X_public, num_queries, engine)
-    return encode_update(upd)
+    with _silo_turn(party):
+        upd, _ = party.local_round(key, X_public, num_queries, engine)
+        with obs.span("fedkt.encode"):
+            return encode_update(upd)
 
 
 class InProcessTransport(TransportBase):
@@ -129,7 +140,7 @@ class ThreadTransport(TransportBase):
         workers = self.parallelism or len(parties)
         ex = ThreadPoolExecutor(max_workers=workers)
         try:
-            futs = [ex.submit(_encoded_round, p, k, X_public,
+            futs = [ex.submit(obs.carry(_encoded_round), p, k, X_public,
                               num_queries, engine)
                     for p, k in zip(parties, keys)]
             return [_decode_annotated(f.result()) for f in futs]

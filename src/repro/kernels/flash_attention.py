@@ -99,6 +99,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
     return pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),

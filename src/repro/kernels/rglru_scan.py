@@ -58,6 +58,7 @@ def rglru_scan(x, log_a, h0, *, block_s=256, block_d=256, interpret=False):
     kern = functools.partial(_kernel, bs=bs, ns=ns)
     h, h_last = pl.pallas_call(
         kern,
+        name="rglru_scan",
         grid=(B, nd, ns),
         in_specs=[
             pl.BlockSpec((1, bs, bd), lambda b, id_, it: (b, it, id_)),

@@ -98,6 +98,7 @@ def tree_hist(xb, node, w, *, num_nodes, num_bins, block_s=512,
                              num_bins=num_bins, bs=bs, bf=bf)
     out = pl.pallas_call(
         kern,
+        name="tree_hist",
         grid=(nf, ns),
         in_specs=[
             pl.BlockSpec((bf, bs), lambda i_f, i_s: (i_f, i_s)),

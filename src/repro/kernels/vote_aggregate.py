@@ -114,6 +114,7 @@ def vote_aggregate(preds, noise, *, num_classes, block_t=128, block_u=512,
     row = pl.BlockSpec((1, bt), lambda it, iu: (0, it))
     outs = pl.pallas_call(
         kern,
+        name="vote_aggregate",
         grid=(nt, nu),
         in_specs=[
             pl.BlockSpec((M, bt), lambda it, iu: (0, it)),

@@ -74,6 +74,7 @@ def wkv6(r, k, v, w, u, s0, *, block_s=256, interpret=False):
     kern = functools.partial(_kernel, bs=bs, ns=ns, dh=dh)
     o, s_last = pl.pallas_call(
         kern,
+        name="wkv6",
         grid=(B, H, ns),
         in_specs=[
             pl.BlockSpec((1, 1, bs, dh), lambda b, h, it: (b, h, it, 0)),

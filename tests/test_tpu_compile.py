@@ -23,6 +23,7 @@ import: only one process may load the TPU library, and every test worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,4 +105,9 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     fn, *args = _cases(one_chip)[kernel]
     compiled = jax.jit(fn).lower(*args).compile()
     print(kernel, compiled.memory_analysis())
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's own name, which a device trace shows for its op (the
+    # leaf build runs the tree_hist kernel)
+    name = "tree_hist" if kernel == "node_hist" else kernel
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call", text)

@@ -43,13 +43,40 @@ def make_bins(X: np.ndarray, num_bins: int = NUM_BINS) -> np.ndarray:
 def binize(X, edges) -> jnp.ndarray:
     """X: (N, F) -> int32 bins (N, F) in [0, num_bins).
 
-    bin = #{edges e : x >= e}, computed as a per-feature searchsorted
-    (edges are sorted ascending) — O(N F log B) instead of the old
-    O(N F B) broadcast-compare, and no (N, F, B) intermediate.
+    bin = #{edges e : x >= e} (edges sorted ascending), counted by
+    comparing x with all B - 1 edges and summing; the compare fuses
+    into the sum, so no (N, F, B) array is stored.  A binary search
+    (``searchsorted``'s default) does O(log B) steps instead of O(B),
+    but on the TPU it is a ``while`` loop of gathers, far slower than
+    B - 1 vector compares at B = 32.  The comparator is searchsorted's
+    own, so NaNs and signed zeros bin as the binary search bins them.
     """
     return jax.vmap(
-        lambda col, e: jnp.searchsorted(e, col, side="right"),
+        lambda col, e: jnp.searchsorted(e, col, side="right",
+                                        method="compare_all"),
         in_axes=(1, 0), out_axes=1)(X, edges).astype(jnp.int32)
+
+
+def _select(table, idx):
+    """``table[..., idx]``: one entry of a small table's last axis.
+
+    A compare of ``idx`` against the axis's K positions, a select and a
+    sum.  On the TPU a gather is a serial lookup per row; across a
+    static axis of a few dozen entries, this is a few vector ops.
+    Exact: one term is kept and the others are zeros (a -0.0 entry
+    reads +0.0).  ``idx`` broadcasts against ``table``'s leading axes.
+    """
+    hit = idx[..., None] == jnp.arange(table.shape[-1], dtype=idx.dtype)
+    return jnp.sum(jnp.where(hit, table, jnp.zeros((), table.dtype)),
+                   axis=-1)
+
+
+def _route(node, xb, feat, thr):
+    """One level down: each row goes right where its split feature's bin
+    exceeds the split bin.  node: (N,) ids within the level; feat/thr:
+    (2^level,) the level's split table."""
+    go_right = _select(xb, _select(feat, node)) > _select(thr, node)
+    return 2 * node + go_right.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -96,10 +123,7 @@ def fit_tree_gini(xb, y, w, feat_mask, *, depth, num_classes,
         split_feat = jax.lax.dynamic_update_slice(split_feat, bf, (base,))
         split_bin = jax.lax.dynamic_update_slice(split_bin, bb, (base,))
 
-        f_n = bf[node]                                     # (N,)
-        b_n = bb[node]
-        go_right = xb[jnp.arange(N), f_n] > b_n
-        node = 2 * node + go_right.astype(jnp.int32)
+        node = _route(node, xb, bf, bb)
 
     # leaves: class histograms
     leaf = ops.node_hist(node, wc, num_nodes=2 ** depth, impl=impl).T
@@ -114,11 +138,10 @@ def tree_apply(tree, xb):
     depth = int(np.log2(leaf.shape[0]))
     node = jnp.zeros((N,), jnp.int32)
     for level in range(depth):
-        base = 2 ** level - 1
-        f = split_feat[base + node]
-        b = split_bin[base + node]
-        node = 2 * node + (xb[jnp.arange(N), f] > b).astype(jnp.int32)
-    return leaf[node]
+        base, n_nodes = 2 ** level - 1, 2 ** level
+        node = _route(node, xb, split_feat[base:base + n_nodes],
+                      split_bin[base:base + n_nodes])
+    return _select(leaf.T, node[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -240,8 +263,7 @@ def fit_tree_gh(xb, g, h, *, depth, num_bins=NUM_BINS, lam=1.0,
         bb = (flat_best % num_bins).astype(jnp.int32)
         split_feat = jax.lax.dynamic_update_slice(split_feat, bf, (base,))
         split_bin = jax.lax.dynamic_update_slice(split_bin, bb, (base,))
-        f_n, b_n = bf[node], bb[node]
-        node = 2 * node + (xb[jnp.arange(N), f_n] > b_n).astype(jnp.int32)
+        node = _route(node, xb, bf, bb)
 
     GHs = ops.node_hist(node, gh_w, num_nodes=2 ** depth, impl=impl)
     leaf = (-GHs[0] / (GHs[1] + lam))[:, None]

@@ -18,6 +18,12 @@ primitives Mosaic cannot lower).
   rglru_scan      recurrentgemma-2b: d = 2560, 2,048 steps
   wkv6            rwkv6-7b: 64 heads of 64 x 64 state, 512 steps
 
+The four stacked tree programs are compiled the same way, at that
+round's widths (6 models of 20 trees or 2 boosting rounds, depth 6, 14
+features x 32 bins, a 2,048-row bucket, 6,105 queries), and must route
+and bin rows without a gather: on the TPU each per-row lookup into a
+small table is a serial gather, and a binary search a ``while`` of them.
+
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every test worker
 imports every test file.
@@ -30,6 +36,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import trees as T
 from repro.kernels import ops
 
 
@@ -111,3 +118,49 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     # leaf build runs the tree_hist kernel)
     name = "tree_hist" if kernel == "node_hist" else kernel
     assert re.search(rf"%{name}(\.\d+)? = .*custom-call", text)
+
+
+def _tree_programs(chip):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    k, M, F, B, depth, n_trees, rounds, N = 6, 2048, 14, 32, 6, 20, 2, 6105
+    X, edges = s((k, M, F), jnp.float32), s((k, F, B - 1), jnp.float32)
+    y, Xq = s((k, M), jnp.int32), s((N, F), jnp.float32)
+
+    def trees(n, C):
+        return (s((k, n, 2 ** depth - 1), jnp.int32),
+                s((k, n, 2 ** depth - 1), jnp.int32),
+                s((k, n, 2 ** depth, C), jnp.float32))
+
+    return {
+        "fit_forest_stacked": (
+            lambda X, e, y, w, fm: T.fit_forest_stacked(
+                X, e, y, w, fm, depth=depth, num_classes=2, impl="kernel"),
+            X, edges, y, s((k, n_trees, M), jnp.float32),
+            s((k, n_trees, F), jnp.float32)),
+        "fit_gbdt_stacked": (
+            lambda X, e, y, w: T.fit_gbdt_stacked(
+                X, e, y, w, 0.3, num_rounds=rounds, depth=depth,
+                impl="kernel"),
+            X, edges, y, s((k, M), jnp.float32)),
+        "predict_forest_stacked": (
+            T.predict_forest_stacked, trees(n_trees, 2), Xq, edges),
+        "predict_gbdt_stacked": (
+            T.predict_gbdt_stacked, trees(rounds, 1), Xq, edges,
+            s((), jnp.float32)),
+    }
+
+
+@pytest.mark.parametrize("program", ["fit_forest_stacked",
+                                     "fit_gbdt_stacked",
+                                     "predict_forest_stacked",
+                                     "predict_gbdt_stacked"])
+def test_tree_program_compiles_gather_free(program, one_chip,
+                                           no_persistent_cache):
+    fn, *args = _tree_programs(one_chip)[program]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert not re.findall(r" gather\(", text)
+    # the boosting lax.scan is the one loop; binning has none
+    loops = 1 if program == "fit_gbdt_stacked" else 0
+    assert len(re.findall(r" while\(", text)) == loops

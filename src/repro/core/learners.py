@@ -184,24 +184,21 @@ class RFLearner:
         at its TRUE size (key-for-key identical to serial ``fit``); rows
         padding up to the shared pow2 bucket carry ZERO sample weight,
         so the stacked states are bit-identical to the serial loop
-        regardless of bucket size (histograms ignore w == 0 rows)."""
-        rf = self._rf()
+        regardless of bucket size (histograms ignore w == 0 rows).  The
+        draws are made, padded and stacked on the device in one program
+        (``RandomForest.bootstrap_stacked``): nothing is pulled to the
+        host."""
         Xs = [_mask_cols(X, self.feature_mask).astype(np.float32)
               for X in Xs]
         bucket = shared_bucket(Xs)
         edges = jnp.asarray(np.stack([T.make_bins(X) for X in Xs]))
-        w, fm = zip(*(rf.bootstrap(kk, len(X), X.shape[1])
-                      for kk, X in zip(keys, Xs)))
-        w = [np.asarray(w_i) for w_i in w]
+        w, fm = self._rf().bootstrap_stacked(
+            keys, tuple(len(X) for X in Xs), bucket, Xs[0].shape[1])
         with _pad_span(Xs, bucket):
-            wp = np.zeros((len(Xs), self.num_trees, bucket), np.float32)
-            for wp_i, w_i in zip(wp, w):
-                wp_i[:, :w_i.shape[1]] = w_i
             Xp, yp, _ = zip(*(_pad_pow2(X, np.asarray(y), bucket=bucket)
                               for X, y in zip(Xs, ys)))
         forest = T.fit_forest_stacked(
-            jnp.stack(Xp), edges, jnp.stack(yp),
-            jnp.asarray(wp), jnp.stack(fm),
+            jnp.stack(Xp), edges, jnp.stack(yp), w, fm,
             depth=self.depth, num_classes=self.num_classes,
             impl=self.impl)
         return (forest, edges)

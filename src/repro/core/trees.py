@@ -201,11 +201,11 @@ class RandomForest:
     feature_frac: float = 0.7
     impl: str = "auto"            # histogram backend (ops.tree_hist)
 
+    @functools.partial(jax.jit, static_argnums=(0, 2, 3))
     def bootstrap(self, key, N, F):
         """Per-tree bootstrap weights (T, N) and feature masks (T, F).
-        Drawn at the TRUE dataset size N — the stacked fit calls this
-        per dataset before padding, so a teacher's draw never depends on
-        the shared bucket and key usage matches ``fit`` split-for-split."""
+        Drawn at the TRUE dataset size N, so a draw never depends on a
+        bucket and key usage matches ``fit`` split-for-split."""
         kb, kf = jax.random.split(key)
         # bootstrap via draw-with-replacement counts as sample weights
         # (multinomial(N, uniform) == histogram of N uniform draws)
@@ -216,6 +216,18 @@ class RandomForest:
               < self.feature_frac).astype(jnp.float32)
         fm = jnp.maximum(fm, jnp.zeros_like(fm).at[:, 0].set(1.0))
         return w, fm
+
+    @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
+    def bootstrap_stacked(self, keys, sizes, bucket, F):
+        """k members' draws as one program: member i is ``bootstrap(
+        keys[i], sizes[i], F)``, its weights zero-padded on the device
+        to (T, bucket).  Returns w (k, T, bucket) and fm (k, T, F);
+        one compile per (sizes, bucket, F)."""
+        w, fm = zip(*(self.bootstrap(keys[i], n, F)
+                      for i, n in enumerate(sizes)))
+        w = [jnp.pad(w_i, ((0, 0), (0, bucket - n)))
+             for w_i, n in zip(w, sizes)]
+        return jnp.stack(w), jnp.stack(fm)
 
     def fit(self, key, X, y, edges):
         xb = binize(X, edges)

@@ -23,6 +23,9 @@ round's widths (6 models of 20 trees or 2 boosting rounds, depth 6, 14
 features x 32 bins, a 2,048-row bucket, 6,105 queries), and must route
 and bin rows without a gather: on the TPU each per-row lookup into a
 small table is a serial gather, and a binary search a ``while`` of them.
+So must the forests' stacked bootstrap draw, at the round's student
+sizes (2 x 6,105 rows into 8,192) and at the 8,215-row forest silo's
+teacher sizes (6 members of 2,738-2,739 rows into 4,096).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every test worker
@@ -133,7 +136,15 @@ def _tree_programs(chip):
                 s((k, n, 2 ** depth - 1), jnp.int32),
                 s((k, n, 2 ** depth, C), jnp.float32))
 
+    rf = T.RandomForest(num_trees=n_trees, depth=depth, num_classes=2)
+
+    def draw(sizes, bucket):
+        return (lambda keys: rf.bootstrap_stacked(keys, sizes, bucket, F),
+                s((len(sizes), 2), jnp.uint32))
+
     return {
+        "bootstrap_stacked_students": draw((N, N), 8192),
+        "bootstrap_stacked_teachers": draw((2739, 2738, 2738) * 2, 4096),
         "fit_forest_stacked": (
             lambda X, e, y, w, fm: T.fit_forest_stacked(
                 X, e, y, w, fm, depth=depth, num_classes=2, impl="kernel"),
@@ -152,7 +163,9 @@ def _tree_programs(chip):
     }
 
 
-@pytest.mark.parametrize("program", ["fit_forest_stacked",
+@pytest.mark.parametrize("program", ["bootstrap_stacked_students",
+                                     "bootstrap_stacked_teachers",
+                                     "fit_forest_stacked",
                                      "fit_gbdt_stacked",
                                      "predict_forest_stacked",
                                      "predict_gbdt_stacked"])

@@ -70,6 +70,72 @@ def test_stacked_tree_fits_bit_identical_to_serial():
             np.testing.assert_array_equal(preds[i], row)
 
 
+@pytest.mark.parametrize("sizes,bucket", [
+    ((40, 70, 130), 256),
+    ((1342, 1341, 1341, 1342, 1341, 1341), 2048),   # a forest silo's teachers
+    ((6105, 6105), 8192),                           # its two students
+    ((64, 256), 256),                               # a member fills the bucket
+])
+def test_forest_bootstrap_stacked_matches_padded_serial_draws(sizes, bucket):
+    """The device-side draw-pad-stack equals, exactly, each member's own
+    draw at its true size run op by op, pulled and zero-padded on the
+    host; the jitted serial ``bootstrap`` equals it too."""
+    rf = T.RandomForest(num_trees=20, depth=6, num_classes=2)
+    F = 14
+    keys = jax.random.split(jax.random.PRNGKey(2 ** 31 + 11), len(sizes))
+    w, fm = rf.bootstrap_stacked(keys, sizes, bucket, F)
+    assert w.shape == (len(sizes), 20, bucket) and fm.shape == (
+        len(sizes), 20, F)
+    for i, n in enumerate(sizes):
+        with jax.disable_jit():
+            w_ref, fm_ref = map(np.asarray, rf.bootstrap(keys[i], n, F))
+        want = np.zeros((20, bucket), np.float32)
+        want[:, :n] = w_ref
+        np.testing.assert_array_equal(np.asarray(w[i]), want)
+        np.testing.assert_array_equal(np.asarray(fm[i]), fm_ref)
+        for got, ref in zip(rf.bootstrap(keys[i], n, F), (w_ref, fm_ref)):
+            np.testing.assert_array_equal(np.asarray(got), ref)
+
+
+class _HostOnlyNumpy:
+    """numpy for the learners module, refusing device arrays: on the CPU
+    a device array already lives in host memory, so the transfer guard
+    lets ``np.asarray(device_array)`` through there unseen."""
+
+    def __getattr__(self, name):
+        attr = getattr(np, name)
+        if isinstance(attr, type) or not callable(attr):
+            return attr
+
+        def refuse_device_arrays(*args, **kwargs):
+            if any(isinstance(a, jax.Array) for a in args):
+                raise AssertionError(f"np.{name} pulled a device array")
+            return attr(*args, **kwargs)
+        return refuse_device_arrays
+
+
+@pytest.mark.parametrize("case", ["teachers", "students"])
+def test_forest_fit_stacked_pulls_nothing_to_host(case, monkeypatch):
+    """A stacked forest fit on host data makes no device-to-host
+    transfer: the bootstrap weights are drawn and padded on the device.
+    Teachers are members of unequal sizes; students share one X."""
+    from repro.core import learners
+    monkeypatch.setattr(learners, "np", _HostOnlyNumpy())
+    rng = np.random.default_rng(7)
+    sizes = (40, 70, 130) if case == "teachers" else (90, 90)
+    X = rng.normal(0, 1, (max(sizes), 6)).astype(np.float32)
+    Xs = [X[:n] for n in sizes]
+    ys = [(Xi[:, 0] > 0).astype(np.int32) for Xi in Xs]
+    if case == "students":
+        ys[1] = 1 - ys[1]
+    keys = jax.random.split(jax.random.PRNGKey(9), len(sizes))
+    rf = RFLearner(num_classes=2, num_trees=6, depth=4)
+    with jax.transfer_guard_device_to_host("disallow"):
+        forest, edges = rf.fit_stacked(keys, Xs, ys)
+    assert edges.shape[0] == len(sizes)
+    assert jax.tree.leaves(forest)[0].shape[:2] == (len(sizes), 6)
+
+
 def test_binize_matches_broadcast_compare():
     """binize == the O(N*F*B) broadcast-compare sum(X >= edges) and ==
     numpy's per-feature searchsorted, including ties ON edges and

@@ -158,56 +158,56 @@ class NNLearner:
             states, jnp.asarray(_mask_cols(X, self.feature_mask)))
 
 
+def _only_member(stacked):
+    """The state of a one-member stacked state."""
+    return jax.tree.map(lambda a: a[0], stacked)
+
+
+def _stack_of_one(state):
+    """``state`` as a stacked state of one member."""
+    return jax.tree.map(lambda a: a[None], state)
+
+
 @dataclass(frozen=True)
 class RFLearner:
+    """Random forest.  A serial ``fit``/``predict`` is the stacked one
+    of a single member at its own pow2 bucket, so member i of any
+    stacked fit equals ``fit(keys[i], Xs[i], ys[i])`` bit for bit."""
     num_classes: int
     num_trees: int = 20
     depth: int = 6
-    impl: str = "auto"            # ops.tree_hist backend knob
     feature_mask: Any = None      # vertical: this silo's columns
 
-    def _rf(self):
-        return T.RandomForest(self.num_trees, self.depth, self.num_classes,
-                              impl=self.impl)
-
     def fit(self, key, X, y):
-        X = _mask_cols(X, self.feature_mask).astype(np.float32)
-        edges = jnp.asarray(T.make_bins(X))
-        forest = self._rf().fit(key, jnp.asarray(X),
-                                jnp.asarray(y, jnp.int32), edges)
-        return (forest, edges)
+        return _only_member(self.fit_stacked(jnp.asarray(key)[None],
+                                             [X], [y]))
 
     def fit_stacked(self, keys, Xs, ys):
         """k forests as one stacked jit fit (federation vmap engine).
 
         Each dataset keeps its own quantile edges and a bootstrap draw
-        at its TRUE size (key-for-key identical to serial ``fit``); rows
-        padding up to the shared pow2 bucket carry ZERO sample weight,
-        so the stacked states are bit-identical to the serial loop
-        regardless of bucket size (histograms ignore w == 0 rows).  The
-        draws are made, padded and stacked on the device in one program
+        at its TRUE size; rows padding up to the shared pow2 bucket
+        carry ZERO sample weight, so a member's state does not depend
+        on the bucket (histograms ignore w == 0 rows).  The draws are
+        made, padded and stacked on the device in one program
         (``RandomForest.bootstrap_stacked``): nothing is pulled to the
         host."""
         Xs = [_mask_cols(X, self.feature_mask).astype(np.float32)
               for X in Xs]
         bucket = shared_bucket(Xs)
         edges = jnp.asarray(np.stack([T.make_bins(X) for X in Xs]))
-        w, fm = self._rf().bootstrap_stacked(
+        w, fm = T.RandomForest(self.num_trees).bootstrap_stacked(
             keys, tuple(len(X) for X in Xs), bucket, Xs[0].shape[1])
         with _pad_span(Xs, bucket):
             Xp, yp, _ = zip(*(_pad_pow2(X, np.asarray(y), bucket=bucket)
                               for X, y in zip(Xs, ys)))
         forest = T.fit_forest_stacked(
             jnp.stack(Xp), edges, jnp.stack(yp), w, fm,
-            depth=self.depth, num_classes=self.num_classes,
-            impl=self.impl)
+            depth=self.depth, num_classes=self.num_classes)
         return (forest, edges)
 
     def predict(self, state, X):
-        forest, edges = state
-        X = _mask_cols(X, self.feature_mask)
-        return self._rf().predict(forest, jnp.asarray(X, jnp.float32),
-                                  edges)
+        return self.predict_stacked(_stack_of_one(state), X)[0]
 
     def predict_stacked(self, states, X):
         """(k, T) predictions of k stacked forests on one shared X."""
@@ -219,27 +219,19 @@ class RFLearner:
 
 @dataclass(frozen=True)
 class GBDTLearner:
+    """Binary GBDT; serial ``fit``/``predict`` as in ``RFLearner``."""
     num_classes: int = 2
     num_rounds: int = 30
     depth: int = 6
-    impl: str = "auto"            # ops.tree_hist backend knob
     feature_mask: Any = None      # vertical: this silo's columns
 
-    def _gb(self):
-        return T.GBDT(self.num_rounds, self.depth, impl=self.impl)
-
     def fit(self, key, X, y):
-        X = _mask_cols(X, self.feature_mask).astype(np.float32)
-        edges = jnp.asarray(T.make_bins(X))
-        gb = self._gb()
-        return (gb.fit(key, jnp.asarray(X), jnp.asarray(y, jnp.int32),
-                       edges), edges)
+        return _only_member(self.fit_stacked(jnp.asarray(key)[None],
+                                             [X], [y]))
 
     def fit_stacked(self, keys, Xs, ys):
         """k GBDTs as one stacked jit fit.  Shared pow2 bucket; padding
-        rows carry zero g/h weight, so stacked == serial bit-for-bit
-        (see trees.fit_gbdt)."""
-        gb = self._gb()
+        rows carry zero g/h weight (see trees.fit_gbdt)."""
         Xs = [_mask_cols(X, self.feature_mask).astype(np.float32)
               for X in Xs]
         bucket = shared_bucket(Xs)
@@ -249,21 +241,19 @@ class GBDTLearner:
                                for X, y in zip(Xs, ys)))
         trees = T.fit_gbdt_stacked(
             jnp.stack(Xp), edges, jnp.stack(yp), jnp.stack(wp),
-            gb.learning_rate, num_rounds=self.num_rounds, depth=self.depth,
-            impl=self.impl)
+            T.GBDT_LEARNING_RATE, num_rounds=self.num_rounds,
+            depth=self.depth)
         return (trees, edges)
 
     def predict(self, state, X):
-        trees, edges = state
-        X = _mask_cols(X, self.feature_mask)
-        return self._gb().predict(trees, jnp.asarray(X, np.float32), edges)
+        return self.predict_stacked(_stack_of_one(state), X)[0]
 
     def predict_stacked(self, states, X):
         """(k, T) predictions of k stacked GBDTs on one shared X."""
         trees, edges = states
         X = _mask_cols(X, self.feature_mask)
         return T.predict_gbdt_stacked(trees, jnp.asarray(X, np.float32),
-                                      edges, self._gb().learning_rate)
+                                      edges, T.GBDT_LEARNING_RATE)
 
 
 @dataclass(frozen=True, eq=False)

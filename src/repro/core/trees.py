@@ -13,9 +13,10 @@ Trees are complete binary trees in heap layout:
   split_feat/split_bin : (2^depth - 1,)  internal nodes
   leaf                 : (2^depth, C)    class scores / regression values
 
-Every fit takes an ``impl`` knob ("auto" | "kernel" |
+The fit functions take an ``impl`` parameter ("auto" | "kernel" |
 "kernel_interpret" | "xla") forwarded to ``ops.tree_hist`` — the same
-dispatch convention as ``ops.votes``.
+dispatch convention as ``ops.votes``.  It is a parameter of the fit
+functions only: the learners call them at "auto".
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import numpy as np
 from repro.kernels import ops
 
 NUM_BINS = 32
+GBDT_LEARNING_RATE = 0.3
 
 
 # ---------------------------------------------------------------------------
@@ -195,11 +197,9 @@ def predict_forest_stacked(forests, X, edges):
 
 @dataclass(frozen=True)
 class RandomForest:
+    """A forest's bootstrap draw; the fit is ``fit_forest_stacked``."""
     num_trees: int = 20
-    depth: int = 6
-    num_classes: int = 2
     feature_frac: float = 0.7
-    impl: str = "auto"            # histogram backend (ops.tree_hist)
 
     @functools.partial(jax.jit, static_argnums=(0, 2, 3))
     def bootstrap(self, key, N, F):
@@ -228,18 +228,6 @@ class RandomForest:
         w = [jnp.pad(w_i, ((0, 0), (0, bucket - n)))
              for w_i, n in zip(w, sizes)]
         return jnp.stack(w), jnp.stack(fm)
-
-    def fit(self, key, X, y, edges):
-        xb = binize(X, edges)
-        N, F = xb.shape
-        w, fm = self.bootstrap(key, N, F)
-        return fit_forest(xb, y, w, fm, depth=self.depth,
-                          num_classes=self.num_classes, impl=self.impl)
-
-    def predict(self, forest, X, edges):
-        xb = binize(X, edges)
-        return jnp.argmax(_forest_probs(forest, xb),
-                          axis=-1).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -335,25 +323,3 @@ def predict_gbdt_stacked(trees, X, edges, lr):
         return (_gbdt_logits(ti, binize(X, ei), lr) > 0).astype(jnp.int32)
 
     return jax.vmap(one)(trees, edges)
-
-
-@dataclass(frozen=True)
-class GBDT:
-    num_rounds: int = 30
-    depth: int = 6
-    learning_rate: float = 0.3
-    num_classes: int = 2  # binary only
-    impl: str = "auto"            # histogram backend (ops.tree_hist)
-
-    def fit(self, key, X, y, edges, w=None):
-        xb = binize(X, edges)
-        if w is None:
-            w = jnp.ones((xb.shape[0],), jnp.float32)
-        return fit_gbdt(xb, y, w, self.learning_rate,
-                        num_rounds=self.num_rounds, depth=self.depth,
-                        impl=self.impl)
-
-    def predict(self, trees, X, edges):
-        xb = binize(X, edges)
-        return (_gbdt_logits(trees, xb, self.learning_rate)
-                > 0).astype(jnp.int32)

@@ -41,12 +41,6 @@ serial predicts + one histogram build, or the LM path's fused
 label step.  Every engine must return the labels AND the CLEAN
 (pre-noise) top1-top2 gap the Lemma-7 accountant needs, bit-identical
 to serial predicts + ``core.voting.teacher_vote`` at the same key.
-
-Kernel-backend contract: engines never pick numeric backends.  A
-learner carries its own knobs (e.g. the tree learners' ``impl`` field
-selecting the ``ops.tree_hist`` histogram backend) and both engines
-call the same learner methods, so a backend choice can never diverge
-between the serial and batched paths.
 """
 from __future__ import annotations
 

@@ -136,7 +136,7 @@ def _tree_programs(chip):
                 s((k, n, 2 ** depth - 1), jnp.int32),
                 s((k, n, 2 ** depth, C), jnp.float32))
 
-    rf = T.RandomForest(num_trees=n_trees, depth=depth, num_classes=2)
+    rf = T.RandomForest(num_trees=n_trees)
 
     def draw(sizes, bucket):
         return (lambda keys: rf.bootstrap_stacked(keys, sizes, bucket, F),

@@ -70,6 +70,19 @@ def test_stacked_tree_fits_bit_identical_to_serial():
             np.testing.assert_array_equal(preds[i], row)
 
 
+def test_serial_gbdt_fits_compile_once_per_bucket():
+    """A serial fit is the stacked fit of one member at its own pow2
+    bucket: 70 and 100 rows share bucket 128, so the two fits add one
+    compiled boosting program, not one per row count."""
+    rng = np.random.default_rng(4)
+    learner = GBDTLearner(num_rounds=3, depth=2)
+    before = T.fit_gbdt_stacked._cache_size()
+    for n in (70, 100):
+        X = rng.normal(0, 1, (n, 5)).astype(np.float32)
+        learner.fit(jax.random.PRNGKey(n), X, (X[:, 0] > 0).astype(np.int32))
+    assert T.fit_gbdt_stacked._cache_size() - before == 1
+
+
 @pytest.mark.parametrize("sizes,bucket", [
     ((40, 70, 130), 256),
     ((1342, 1341, 1341, 1342, 1341, 1341), 2048),   # a forest silo's teachers
@@ -80,7 +93,7 @@ def test_forest_bootstrap_stacked_matches_padded_serial_draws(sizes, bucket):
     """The device-side draw-pad-stack equals, exactly, each member's own
     draw at its true size run op by op, pulled and zero-padded on the
     host; the jitted serial ``bootstrap`` equals it too."""
-    rf = T.RandomForest(num_trees=20, depth=6, num_classes=2)
+    rf = T.RandomForest(num_trees=20)
     F = 14
     keys = jax.random.split(jax.random.PRNGKey(2 ** 31 + 11), len(sizes))
     w, fm = rf.bootstrap_stacked(keys, sizes, bucket, F)
